@@ -47,6 +47,10 @@ def main() -> None:
                     help="write executed sections' rows to PATH as JSON")
     args = ap.parse_args()
 
+    from repro import compile_cache
+
+    compile_cache.enable()
+
     chosen = args.only.split(",") if args.only else list(SECTIONS)
     unknown = [k for k in chosen if k not in SECTIONS]
     if unknown:

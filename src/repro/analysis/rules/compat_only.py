@@ -1,18 +1,19 @@
 """CB1xx — the compat-layer-only guardrail (ROADMAP, PR 1).
 
-All JAX-version drift is funneled through ``src/repro/compat.py``; the
-rest of the tree must never touch the drifting spellings directly:
+The JAX call sites whose spelling tends to drift live in
+``src/repro/compat.py``; the rest of the tree never touches them
+directly, so a future rename is a one-file change:
 
-  * CB101: ``pltpu.CompilerParams`` / ``pltpu.TPUCompilerParams`` —
-    renamed across 0.4.x/0.6; use ``compat.tpu_compiler_params``.
+  * CB101: ``pltpu.CompilerParams`` (once named ``TPUCompilerParams``) —
+    use ``compat.tpu_compiler_params``.
   * CB102: ``pl.pallas_call`` — every TPU call site goes through
     ``compat.pallas_call_tpu`` so ``dimension_semantics``/``interpret``
     handling stays centralized.
   * CB103: ``jax.shard_map`` / ``jax.experimental.shard_map`` — the
-    location and the ``check_rep``/``check_vma`` kwarg both drifted;
+    location and the ``check_rep``/``check_vma`` kwarg have both moved;
     use ``compat.shard_map``.
-  * CB104: ``axis_types=`` — the kwarg doesn't exist on 0.4.x; use
-    ``compat.make_mesh`` / ``compat.mesh_axis_types``.
+  * CB104: ``axis_types=`` — mesh axis types are chosen in one place;
+    use ``compat.make_mesh``.
 
 ``compat.py`` itself is exempt — it is the one place these spellings
 are supposed to live.
@@ -99,7 +100,7 @@ def check_shard_map(ctx: FileContext) -> Iterator[Finding]:
 
 
 @rule("CB104", "compat-axis-types",
-      "axis_types= doesn't exist on JAX 0.4.x; use compat.make_mesh")
+      "mesh axis types are chosen in compat.make_mesh alone")
 def check_axis_types(ctx: FileContext) -> Iterator[Finding]:
     if _is_compat(ctx):
         return
@@ -109,5 +110,4 @@ def check_axis_types(ctx: FileContext) -> Iterator[Finding]:
                 if kw.arg == "axis_types":
                     yield _at(ctx, node, "CB104",
                               "axis_types= kwarg outside compat.py",
-                              "use repro.compat.make_mesh / "
-                              "mesh_axis_types")
+                              "use repro.compat.make_mesh")

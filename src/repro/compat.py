@@ -1,27 +1,23 @@
-"""Version-guarded JAX compatibility shims.
+"""The one home of the JAX call sites whose spelling tends to drift.
 
-The repo tracks current JAX APIs but must run on every toolchain the
-container ships (currently 0.4.37). Every API whose name or signature
-drifted between JAX 0.4.x and newer releases is funneled through this
-module so the rest of the codebase is version-agnostic:
+The repo targets the installed JAX (0.9.0) and no other version. These
+shims keep each drift-prone call in one place, so a future rename or
+signature change is a one-file edit (cblint's CB1xx rules keep every
+other module off the direct spellings):
 
-  * ``tpu_compiler_params``  — ``pltpu.CompilerParams`` was called
-    ``TPUCompilerParams`` before jax 0.6.
+  * ``tpu_compiler_params``  — ``pltpu.CompilerParams``.
   * ``pallas_call_tpu``      — one entry point for every Pallas TPU call
     site; centralizes ``dimension_semantics``/``interpret`` handling so
     kernels never touch ``compiler_params`` directly.
-  * ``make_mesh`` / ``mesh_axis_types`` — ``jax.sharding.AxisType`` and
-    the ``axis_types=`` kwarg of ``jax.make_mesh`` don't exist in 0.4.x.
-  * ``shard_map``            — lives at ``jax.experimental.shard_map``
-    with a ``check_rep`` kwarg in 0.4.x, at ``jax.shard_map`` with
-    ``check_vma`` in newer releases.
+  * ``make_mesh``            — ``jax.make_mesh`` with explicit ``Auto``
+    axis types.
+  * ``shard_map``            — ``jax.shard_map``.
 
 Nothing here may import heavyweight repro modules; kernels and launch
 code import *us*.
 """
 from __future__ import annotations
 
-import inspect
 from typing import Any, Callable, Sequence
 
 import jax
@@ -33,20 +29,13 @@ from repro import errors
 # Pallas compiler params
 # ---------------------------------------------------------------------------
 
-# jax >= 0.6 renamed TPUCompilerParams -> CompilerParams; support both and
-# prefer the modern name when present.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
-
 def tpu_compiler_params(
     *, dimension_semantics: Sequence[str] | None = None, **kwargs: Any
 ):
-    """Build the TPU compiler-params object for this JAX version."""
+    """Build the Pallas TPU compiler-params object."""
     if dimension_semantics is not None:
         kwargs["dimension_semantics"] = tuple(dimension_semantics)
-    return _CompilerParams(**kwargs)
+    return pltpu.CompilerParams(**kwargs)
 
 
 def pallas_call_tpu(
@@ -69,11 +58,12 @@ def pallas_call_tpu(
     mirroring ``pl.pallas_call`` itself. Returns the callable to apply to
     the operands.
 
-    ``interpret`` is deliberately required: our kernels default it per
-    backend (interpret off-TPU, compiled on TPU) and a silent default
-    here would make a future TPU call site run the interpreter — slow
-    with no error. Unsupplied grid/spec arguments are left to
-    ``pl.pallas_call``'s own defaults rather than forwarded as ``None``.
+    ``interpret`` is deliberately required, here and on every kernel
+    entry point: ``kernels.ops`` derives it from the backend (interpret
+    off-TPU, compiled on TPU), and a silent default would make a TPU
+    call site run the interpreter — slow with no error. Unsupplied
+    grid/spec arguments are left to ``pl.pallas_call``'s own defaults
+    rather than forwarded as ``None``.
     """
     call_kwargs: dict[str, Any] = dict(
         out_shape=out_shape,
@@ -100,29 +90,13 @@ def pallas_call_tpu(
 # Mesh construction
 # ---------------------------------------------------------------------------
 
-_MAKE_MESH_TAKES_AXIS_TYPES = (
-    "axis_types" in inspect.signature(jax.make_mesh).parameters
-)
-
-
-def mesh_axis_types(num_axes: int) -> dict[str, Any]:
-    """kwargs enabling explicit Auto axis types where the API supports it.
-
-    Returns ``{}`` on JAX 0.4.x (where every mesh axis is implicitly
-    Auto), so call sites can always splat the result into ``make_mesh``.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None or not _MAKE_MESH_TAKES_AXIS_TYPES:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * num_axes}
-
-
 def make_mesh(
     axis_shapes: Sequence[int], axis_names: Sequence[str], **kwargs: Any
 ) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types on versions that take them."""
+    """``jax.make_mesh`` with every axis of ``Auto`` type."""
     return jax.make_mesh(
-        axis_shapes, axis_names, **mesh_axis_types(len(axis_shapes)), **kwargs
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_shapes), **kwargs
     )
 
 
@@ -137,23 +111,8 @@ def shard_map(
     mesh: jax.sharding.Mesh,
     in_specs,
     out_specs,
-    check_vma: bool | None = None,
     **kwargs: Any,
 ):
-    """Version-stable ``shard_map`` (supports ``functools.partial`` use).
-
-    ``check_vma`` follows the modern spelling; it maps onto ``check_rep``
-    for JAX 0.4.x where shard_map still lives under ``jax.experimental``.
-    """
-    modern = getattr(jax, "shard_map", None)
-    if modern is not None:
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        return modern(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)
-    from jax.experimental.shard_map import shard_map as legacy
-
-    if check_vma is not None:
-        kwargs["check_rep"] = check_vma
-    return legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kwargs)
+    """``jax.shard_map`` (supports ``functools.partial`` use)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         **kwargs)

@@ -61,6 +61,19 @@ class ShardedStreams:
         mean = self.device_nnz.mean()
         return float(self.device_nnz.max() / mean) if mean > 0 else 1.0
 
+    def device_put(self, mesh: jax.sharding.Mesh,
+                   axis: str = "model") -> "ShardedStreams":
+        """Place each device's shard on its own device of ``mesh``.
+
+        Every leaf is split along its leading (device) axis over ``axis``,
+        so no device ever holds the whole stack.
+        """
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        sharding = NamedSharding(mesh, P(axis))
+        return dataclasses.replace(
+            self, streams=jax.device_put(self.streams, sharding))
+
 
 def shard_streams(cb: CBMatrix, num_devices: int) -> ShardedStreams:
     """pq-balance CB blocks across devices and build uniform stacked streams."""

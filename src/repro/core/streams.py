@@ -420,6 +420,20 @@ jax.tree_util.register_dataclass(
 )
 
 
+def lane_layout(widths, slot_map: np.ndarray) -> tuple[int, np.ndarray]:
+    """Shared payload width ``W`` and per-(group, member) lane offsets.
+
+    ``slot_map[g, j]`` is the block in member ``j`` of group ``g`` (-1 =
+    empty); members are laid side by side, so ``W = max_g sum(widths)``.
+    Vectorised, so packing stays linear in the block count. Offsets of
+    empty members are meaningless.
+    """
+    w = np.asarray(widths, np.int64)
+    member_w = np.where(slot_map >= 0, w[np.maximum(slot_map, 0)], 0)
+    W = int(member_w.sum(axis=1).max()) if member_w.size else 0
+    return W, np.cumsum(member_w, axis=1) - member_w
+
+
 def build_super_streams(
     cb: CBMatrix, group_size: int | None = None
 ) -> SuperBlockStreams:
@@ -482,20 +496,10 @@ def build_super_streams(
         bal = balance_mod.grid_group_balance(np.asarray(widths, np.int64), Gs)
         ng = bal.num_groups
         slot_map = bal.slots.reshape(ng, Gs)
-        W = 0
-        for g in range(ng):
-            blks = slot_map[g][slot_map[g] >= 0]
-            W = max(W, int(np.sum(np.asarray(widths)[blks])) if len(blks) else 0)
+        W, offsets = lane_layout(widths, slot_map)
         vals = np.zeros((ng, payload_rows, W) if payload_rows else (ng, W), vdt)
         brow = np.zeros((ng, W // SUBLANE), np.int32)
         xidx = np.zeros((ng, W), np.int32)
-        offsets = np.zeros((ng, Gs), np.int64)
-        for g in range(ng):
-            off = 0
-            for member in range(Gs):
-                if slot_map[g, member] >= 0:
-                    offsets[g, member] = off
-                    off += int(widths[slot_map[g, member]])
         return slot_map, offsets, vals, brow, xidx
 
     def _place_brow(brow_arr, g, off, w, brow):

@@ -45,6 +45,7 @@ def _kernel_batched(tiles_ref, xg_ref, out_ref, *, group_size: int,
         xb = xg_ref[0, g]                             # (B,)
         out_ref[0, g, :] = jnp.dot(
             tile.astype(jnp.float32), xb.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )
 
@@ -54,7 +55,7 @@ def block_dense_spmv_batched(
     tiles: jax.Array,   # (gd, G*B, B) stacked super-tiles
     xg: jax.Array,      # (gd, G, B) pre-gathered x values per slot
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Per-slot partials for every super-tile — (gd, G, B) float32."""
     gd, G, B = xg.shape

@@ -16,7 +16,7 @@ splitting a panel's columns across slots is exact). The whole group then
 reduces with
 
     tmp = slab * xg                 elementwise,   (B, W)
-    out = tmp.reshape(B, S, SUBLANE).sum(lanes)    (B, S) -> (S, B)
+    out = tmp.T.reshape(S, SUBLANE, B).sum(axis=1)            (S, B)
 
 — a plain strided lane reduction, O(B*W) work with *no* data-dependent
 segment contraction, so the batched step costs the same FLOPs as the
@@ -46,11 +46,12 @@ from repro import errors
 
 def _panel_kernel_batched(panels_ref, xg_ref, out_ref, *, slots: int):
     slab = panels_ref[0].astype(jnp.float32)   # (B, W)
-    xg = xg_ref[0].astype(jnp.float32)         # (W,)
-    tmp = slab * xg[None, :]                   # (B, W)
+    xg = xg_ref[0].astype(jnp.float32)         # (1, W)
+    tmp = slab * xg                            # (B, W)
     B = slab.shape[0]
-    out = tmp.reshape(B, slots, SUBLANE).sum(axis=2)   # (B, S)
-    out_ref[0] = out.T
+    # Transpose first: Mosaic cannot split the lane axis in place
+    # ((B, W) -> (B, S, SUBLANE)), but splits the sublane axis of (W, B).
+    out_ref[0] = tmp.T.reshape(slots, SUBLANE, B).sum(axis=1)   # (S, B)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -58,9 +59,13 @@ def panel_spmv_batched(
     panels: jax.Array,  # (gp, B, W) lane-packed panel groups, W % SUBLANE == 0
     xg: jax.Array,      # (gp, W) pre-gathered x values
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
-    """Per-slot partial y tiles — (gp, W // SUBLANE, B) float32."""
+    """Per-slot partial y tiles — (gp, W // SUBLANE, B) float32.
+
+    ``xg`` is viewed as ``(gp, 1, W)`` so its ``(1, 1, W)`` block's last
+    two dimensions equal the array's, which Mosaic requires.
+    """
     gp, B, W = panels.shape
     if W % SUBLANE:
         raise errors.InvalidArgError(f"packed width {W} not a multiple of {SUBLANE}")
@@ -70,11 +75,11 @@ def panel_spmv_batched(
         grid=(gp,),
         in_specs=[
             pl.BlockSpec((1, B, W), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, W), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, W), lambda i: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, slots, B), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((gp, slots, B), jnp.float32),
         dimension_semantics=("parallel",),
         interpret=interpret,
         name="cb_colagg_panel_spmv_batched",
-    )(panels, xg)
+    )(panels, xg.reshape(gp, 1, W))

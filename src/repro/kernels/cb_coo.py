@@ -57,9 +57,9 @@ def _decode(codes, B):
 def _coo_kernel_batched(codes_ref, vals_ref, xg_ref, out_ref, *,
                         block_size: int, slots: int):
     B = block_size
-    codes = codes_ref[0]                        # (W,) int32
-    vals = vals_ref[0].astype(jnp.float32)      # (W,)
-    xv = xg_ref[0].astype(jnp.float32)          # (W,) pre-gathered
+    codes = codes_ref[0, 0]                     # (W,) int32
+    vals = vals_ref[0, 0].astype(jnp.float32)   # (W,)
+    xv = xg_ref[0, 0].astype(jnp.float32)       # (W,) pre-gathered
     rows, _ = _decode(codes, B)
     iota = jax.lax.broadcasted_iota(jnp.int32, (codes.shape[0], B), 1)
     onehot = (rows[:, None] == iota).astype(jnp.float32)     # (W, B)
@@ -74,9 +74,15 @@ def coo_spmv_batched(
     xg: jax.Array,     # (gc, W) pre-gathered x values
     *,
     block_size: int,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
-    """Per-slot partial y tiles — (gc, W // SUBLANE, B) float32."""
+    """Per-slot partial y tiles — (gc, W // SUBLANE, B) float32.
+
+    Each operand is viewed as ``(gc, 1, W)`` so a ``(1, 1, W)`` block's
+    last two dimensions equal the array's: Mosaic refuses a ``(1, W)``
+    block over ``(gc, W)`` (the sublane dimension would be neither a
+    multiple of 8 nor the whole axis).
+    """
     gc, W = codes.shape
     if W % SUBLANE:
         raise errors.InvalidArgError(f"packed width {W} not a multiple of {SUBLANE}")
@@ -85,14 +91,10 @@ def coo_spmv_batched(
     return pallas_call_tpu(
         functools.partial(_coo_kernel_batched, block_size=B, slots=slots),
         grid=(gc,),
-        in_specs=[
-            pl.BlockSpec((1, W), lambda i: (i, 0)),
-            pl.BlockSpec((1, W), lambda i: (i, 0)),
-            pl.BlockSpec((1, W), lambda i: (i, 0)),
-        ],
+        in_specs=[pl.BlockSpec((1, 1, W), lambda i: (i, 0, 0))] * 3,
         out_specs=pl.BlockSpec((1, slots, B), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((gc, slots, B), jnp.float32),
         dimension_semantics=("parallel",),
         interpret=interpret,
         name="cb_coo_spmv_batched",
-    )(codes, vals, xg)
+    )(*(a.reshape(gc, 1, W) for a in (codes, vals, xg)))

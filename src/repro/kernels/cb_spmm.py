@@ -19,7 +19,9 @@ Group contract (mirrors ``core/streams.SuperTileStream``):
     slot, because each slot owns its own activation tile. The slab still
     arrives as ONE contiguous DMA, which is where the win is;
   * X tiles are DMA'd per slot through the scalar-prefetched ``bcol``
-    slot map — the virtual-pointer idea (data location resolved from
+    slot map (flattened to 1-D: SMEM pads a 2-D array's minor dimension
+    to 128 words, which at ``Gt = 16`` made a ~2k-group map overflow the
+    1 MiB of SMEM) — the virtual-pointer idea (data location resolved from
     prefetched metadata, payload fetched with a sequential DMA) mapped
     onto Pallas's pipeline. Empty slots carry ``bcol`` 0 and a zero
     tile, so they fetch X block 0 and contribute exact zeros;
@@ -60,9 +62,12 @@ def _spmm_group_kernel(bcol_ref, tiles_ref, *refs, group_size: int,
     xs = jnp.concatenate(
         [x_refs[g][0][None] for g in range(Gt)]
     ).astype(jnp.float32)                              # (Gt, B, bn)
+    # HIGHEST: Mosaic's default f32 contraction is one bf16 pass (~1e-3
+    # relative error on the chip); the engine promises f32 results.
     out_ref[0] = jax.lax.dot_general(
         tiles, xs,
         dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
 
@@ -74,7 +79,7 @@ def super_tile_spmm(
     Xb: jax.Array,      # (nb, B, Npad) — X reshaped into B-row blocks
     *,
     block_n: int = LANE,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Per-slot partial Y tiles — (gt, Gt, B, Npad) float32, ONE pallas_call.
 
@@ -97,7 +102,7 @@ def super_tile_spmm(
             *[
                 pl.BlockSpec(
                     (1, B, block_n),
-                    lambda j, i, bcol, g=g: (bcol[i, g], 0, j),
+                    lambda j, i, bcol, g=g: (bcol[i * Gt + g], 0, j),
                 )
                 for g in range(Gt)
             ],
@@ -113,4 +118,4 @@ def super_tile_spmm(
         dimension_semantics=("parallel", "parallel"),
         interpret=interpret,
         name="cb_super_tile_spmm",
-    )(bcol, tiles, *([Xb] * Gt))
+    )(bcol.reshape(-1), tiles, *([Xb] * Gt))

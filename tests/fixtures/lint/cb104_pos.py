@@ -1,4 +1,4 @@
-"""CB104 positive: axis_types= does not exist on JAX 0.4.x."""
+"""CB104 positive: axis_types= outside compat.make_mesh."""
 import jax
 
 

@@ -188,6 +188,33 @@ def test_balance_beats_naive_on_powerlaw():
     assert balanced <= naive  # Fig. 4 claim
 
 
+def test_lane_layout_matches_per_group_loop():
+    """Packed width W and lane offsets equal the per-group Python loop
+    they replaced, on a ragged width list with empty trailing slots."""
+    from repro.core.balance import grid_group_balance
+    from repro.core.streams import even_group, lane_layout
+
+    widths = (np.random.default_rng(0).integers(1, 40, 37) * 8).tolist()
+    _, Gs = even_group(len(widths), 8)
+    bal = grid_group_balance(np.asarray(widths, np.int64), Gs)
+    slot_map = bal.slots.reshape(bal.num_groups, Gs)
+    assert (slot_map < 0).any()  # the ragged case: some empty members
+    W_loop = 0
+    offsets_loop = np.zeros(slot_map.shape, np.int64)
+    for g in range(slot_map.shape[0]):
+        blks = slot_map[g][slot_map[g] >= 0]
+        W_loop = max(W_loop, int(np.sum(np.asarray(widths)[blks])))
+        off = 0
+        for member in range(Gs):
+            if slot_map[g, member] >= 0:
+                offsets_loop[g, member] = off
+                off += widths[slot_map[g, member]]
+    W, offsets = lane_layout(widths, slot_map)
+    assert W == W_loop
+    placed = slot_map >= 0
+    np.testing.assert_array_equal(offsets[placed], offsets_loop[placed])
+
+
 # ---------------------------------------------------------------------------
 # end-to-end CBMatrix
 # ---------------------------------------------------------------------------
