@@ -1,0 +1,8 @@
+"""Share of the traced window in which no operation ran on the device, in %
+(busy time averaged over the chips used)."""
+
+
+def read(r):
+    if r.trace is None:
+        return None
+    return 100.0 * (1.0 - r.trace["busy_s"] / r.window_s)
