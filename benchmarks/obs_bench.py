@@ -1,4 +1,4 @@
-"""Observability: instrumentation overhead + launch-accounting fidelity.
+"""Observability: instrumentation overhead + launch accounting.
 
 Two claims, per corpus matrix:
 
@@ -12,17 +12,12 @@ Two claims, per corpus matrix:
     recording (or a host sync) into the dispatch path. The eager
     per-call shim cost is µs-scale and reported as ``t_record_us``
     (informational, machine-dependent).
-  * **accounting fidelity** — after one planned ``matvec``, the registry
-    series ``repro.autotune.exec.{padded_elems,steps}`` must carry both
-    a ``kind=measured`` total (what the built streams actually run) and
-    a ``kind=predicted`` total (the plan cost model), and their ratio is
-    the per-call model fidelity — guarded at the same 2x envelope the
-    autotune section uses. ``metrics_present`` asserts every required
-    ``repro.ops.spmv.*`` key landed in the snapshot.
+  * **accounting** — after one planned ``matvec``, ``metrics_present``
+    asserts every required ``repro.ops.spmv.*`` key landed in the
+    snapshot.
 
-Determinism: planning is pinned to heuristic mode and the accounting
-columns are pure preprocessing arithmetic; only the ``t_*`` columns are
-machine-dependent (and guarded as a ratio).
+Determinism: planning is pinned to heuristic mode; only the ``t_*``
+columns are machine-dependent (and guarded as a ratio).
 """
 from __future__ import annotations
 
@@ -49,22 +44,7 @@ REQUIRED_METRICS = (
     "repro.ops.spmv.launches",
     "repro.ops.spmv.steps",
     "repro.ops.spmv.padded_elems",
-    "repro.autotune.exec.calls",
-    "repro.autotune.exec.padded_elems",
-    "repro.autotune.exec.steps",
 )
-
-
-def _series_total(snap: dict, name: str, **labels) -> int:
-    """Sum a counter's series filtered by a label subset."""
-    entry = snap.get(name)
-    if not entry:
-        return 0
-    want = {str(k): str(v) for k, v in labels.items()}
-    return int(sum(
-        s["value"] for s in entry["series"]
-        if all(s["labels"].get(k) == v for k, v in want.items())
-    ))
 
 
 def run(scale="small") -> list[dict]:
@@ -82,7 +62,7 @@ def run(scale="small") -> list[dict]:
                 jnp.float32,
             )
 
-            # -- accounting fidelity: one planned matvec, read the registry
+            # -- accounting: one planned matvec, read the registry
             obs.configure(enabled=True)
             obs.reset()
             op.matvec(x).block_until_ready()
@@ -90,16 +70,6 @@ def run(scale="small") -> list[dict]:
             row = {
                 "matrix": spec.name,
                 "nnz": int(cb.nnz),
-                "padded_elems_measured": _series_total(
-                    snap, "repro.autotune.exec.padded_elems",
-                    kind="measured"),
-                "padded_elems_predicted": _series_total(
-                    snap, "repro.autotune.exec.padded_elems",
-                    kind="predicted"),
-                "steps_measured": _series_total(
-                    snap, "repro.autotune.exec.steps", kind="measured"),
-                "steps_predicted": _series_total(
-                    snap, "repro.autotune.exec.steps", kind="predicted"),
                 "metrics_present": all(m in snap for m in REQUIRED_METRICS),
             }
 
@@ -130,20 +100,13 @@ def run(scale="small") -> list[dict]:
 
 def main(scale="small"):
     rows = run(scale)
-    print("matrix,nnz,t_on_ms,t_off_ms,overhead,t_record_us,"
-          "padded_meas,padded_pred,steps_meas,steps_pred,metrics_ok")
+    print("matrix,nnz,t_on_ms,t_off_ms,overhead,t_record_us,metrics_ok")
     for r in rows:
         print(f"{r['matrix']},{r['nnz']},{r['t_enabled'] * 1e3:.2f},"
               f"{r['t_disabled'] * 1e3:.2f},{r['overhead_ratio']:.3f},"
-              f"{r['t_record_us']:.1f},"
-              f"{r['padded_elems_measured']},{r['padded_elems_predicted']},"
-              f"{r['steps_measured']},{r['steps_predicted']},"
-              f"{int(r['metrics_present'])}")
+              f"{r['t_record_us']:.1f},{int(r['metrics_present'])}")
     g_over = geomean([r["overhead_ratio"] for r in rows])
-    g_model = geomean([r["padded_elems_measured"]
-                       / max(1, r["padded_elems_predicted"]) for r in rows])
-    print(f"GEOMEAN obs-on/obs-off: {g_over:.3f}x; "
-          f"measured/predicted padded elems: {g_model:.3f}x")
+    print(f"GEOMEAN obs-on/obs-off: {g_over:.3f}x")
     return rows
 
 

@@ -8,10 +8,9 @@ ticks on a toy model — both under the default tracer — then:
 
   * writes the spans as Chrome ``trace_event`` JSON (load the file in
     ``chrome://tracing`` / Perfetto);
-  * prints a per-span-name summary table (count / total / mean / max);
-  * prints the metrics snapshot's headline counters, including the
-    per-plan measured-vs-predicted launch accounting so cost-model
-    fidelity is visible at a glance.
+  * prints a per-span-name summary table (count / total / self / mean /
+    max);
+  * prints the metrics snapshot's headline counters.
 
 ``main`` returns the payload dict (trace path, chrome trace object,
 snapshot) so the tier-1 smoke test can validate the export schema
@@ -101,11 +100,11 @@ def main(argv=None) -> dict:
     print(f"\n[chrome trace: {trace_path} — "
           f"{len(trace['traceEvents'])} events]")
 
-    print(f"\n{'span':<24}{'count':>7}{'total_ms':>10}"
+    print(f"\n{'span':<24}{'count':>7}{'total_ms':>10}{'self_ms':>9}"
           f"{'mean_ms':>9}{'max_ms':>9}")
     for row in obs.tracer().summary():
         print(f"{row['name']:<24}{row['count']:>7}"
-              f"{row['total_s'] * 1e3:>10.2f}"
+              f"{row['total_s'] * 1e3:>10.2f}{row['self_s'] * 1e3:>9.2f}"
               f"{row['mean_s'] * 1e3:>9.2f}{row['max_s'] * 1e3:>9.2f}")
 
     print(f"\n{'metric / labels':<58}{'value':>10}")
@@ -123,19 +122,6 @@ def main(argv=None) -> dict:
     for name in headline:
         for labels, value in _counter_rows(snap, name):
             print(f"{name + '{' + labels + '}':<58}{value:>10g}")
-
-    print("\nplan accounting (measured vs predicted, per structure hash):")
-    for metric in ("repro.autotune.exec.padded_elems",
-                   "repro.autotune.exec.steps"):
-        rows = dict(_counter_rows(snap, metric))
-        plans = sorted({lab.split(",")[1] for lab in rows})
-        for plan in plans:
-            meas = rows.get(f"kind=measured,{plan}", 0)
-            pred = rows.get(f"kind=predicted,{plan}", 0)
-            ratio = meas / pred if pred else float("nan")
-            print(f"  {metric.split('.')[-1]:<14}{plan:<24}"
-                  f"measured={meas:<10g}predicted={pred:<10g}"
-                  f"ratio={ratio:.3f}")
 
     print("\nmodeled locality (planned super-streams, LRU line model):")
     print(f"  l1_hit={locality['l1_hit_rate']:.3f} "

@@ -22,7 +22,7 @@ import zlib
 
 import numpy as np
 
-from repro import errors
+from repro import errors, obs
 
 from . import aggregation, balance, blocking, column_agg, formats
 
@@ -115,64 +115,78 @@ class CBMatrix:
         warps_per_tb: int = 8,
         nonfinite: str = "raise",
     ) -> "CBMatrix":
-        val_dtype = np.dtype(val_dtype)
-        thresholds = formats.coerce_thresholds(thresholds)
-        rows = np.asarray(rows)
-        cols = np.asarray(cols)
-        vals = np.asarray(vals, dtype=val_dtype)
-        vals = _nonfinite_policy(vals, nonfinite, "CBMatrix.from_coo")
+        # One ``cb.from_coo.<stage>`` span per stage; the two partitions
+        # share ``cb.from_coo.partition``.
+        with obs.span("cb.from_coo", block_size=block_size):
+            val_dtype = np.dtype(val_dtype)
+            thresholds = formats.coerce_thresholds(thresholds)
+            rows = np.asarray(rows)
+            cols = np.asarray(cols)
+            vals = np.asarray(vals, dtype=val_dtype)
+            vals = _nonfinite_policy(vals, nonfinite, "CBMatrix.from_coo")
 
-        # (1)+(2): probe partition to decide column aggregation (th0 gate).
-        probe = blocking.partition_coo(rows, cols, vals, shape, block_size)
-        if use_column_aggregation == "auto":
-            apply_agg = formats.should_column_aggregate(
-                probe.nnz_per_blk, block_size, thresholds
+            # (1)+(2): probe partition to decide column aggregation (th0).
+            with obs.span("cb.from_coo.partition"):
+                probe = blocking.partition_coo(rows, cols, vals, shape,
+                                               block_size)
+            if use_column_aggregation == "auto":
+                apply_agg = formats.should_column_aggregate(
+                    probe.nnz_per_blk, block_size, thresholds
+                )
+            else:
+                apply_agg = bool(use_column_aggregation)
+
+            # (3): panel-level column compaction.
+            if apply_agg:
+                with obs.span("cb.from_coo.colagg"):
+                    agg = column_agg.column_aggregate(rows, cols, shape,
+                                                      block_size)
+                with obs.span("cb.from_coo.partition"):
+                    part = blocking.partition_coo(
+                        rows, agg.new_cols, vals, shape, block_size)
+            else:
+                agg = column_agg.identity_aggregation(cols, shape, block_size)
+                part = probe
+
+            # (4): per-block format selection.
+            with obs.span("cb.from_coo.formats"):
+                fmts = formats.select_formats(part.nnz_per_blk, block_size,
+                                              thresholds)
+
+            # (5): intra-block aggregation into the flat buffer + VPs.
+            with obs.span("cb.from_coo.aggregate"):
+                elems = [part.block_elems(i) for i in range(part.num_blocks)]
+                packed = aggregation.aggregate_blocks(fmts, elems, block_size,
+                                                      val_dtype)
+
+            # (6): inter-TB load balance (Alg. 2) and metadata permutation.
+            with obs.span("cb.from_coo.balance"):
+                bal = balance.tb_load_balance(part.nnz_per_blk, warps_per_tb)
+                brow, bcol, nnzb, typb, vps = balance.apply_balance(
+                    bal,
+                    part.blk_row_idx,
+                    part.blk_col_idx,
+                    part.nnz_per_blk,
+                    fmts,
+                    packed.vp_per_blk,
+                    pad_values=(0, 0, 0, formats.FMT_COO, 0),
+                )
+
+            return cls(
+                shape=tuple(shape),
+                block_size=block_size,
+                val_dtype=val_dtype,
+                thresholds=thresholds,
+                blk_row_idx=brow,
+                blk_col_idx=bcol,
+                nnz_per_blk=nnzb,
+                type_per_blk=typb,
+                vp_per_blk=vps,
+                packed=packed.packed,
+                colagg=agg,
+                balance_result=bal,
+                nnz=part.nnz,
             )
-        else:
-            apply_agg = bool(use_column_aggregation)
-
-        # (3): panel-level column compaction.
-        if apply_agg:
-            agg = column_agg.column_aggregate(rows, cols, shape, block_size)
-            part = blocking.partition_coo(rows, agg.new_cols, vals, shape, block_size)
-        else:
-            agg = column_agg.identity_aggregation(cols, shape, block_size)
-            part = probe
-
-        # (4): per-block format selection.
-        fmts = formats.select_formats(part.nnz_per_blk, block_size, thresholds)
-
-        # (5): intra-block aggregation into the flat buffer + VPs.
-        elems = [part.block_elems(i) for i in range(part.num_blocks)]
-        packed = aggregation.aggregate_blocks(fmts, elems, block_size, val_dtype)
-
-        # (6): inter-TB load balance (Alg. 2) and metadata permutation.
-        bal = balance.tb_load_balance(part.nnz_per_blk, warps_per_tb)
-        brow, bcol, nnzb, typb, vps = balance.apply_balance(
-            bal,
-            part.blk_row_idx,
-            part.blk_col_idx,
-            part.nnz_per_blk,
-            fmts,
-            packed.vp_per_blk,
-            pad_values=(0, 0, 0, formats.FMT_COO, 0),
-        )
-
-        return cls(
-            shape=tuple(shape),
-            block_size=block_size,
-            val_dtype=val_dtype,
-            thresholds=thresholds,
-            blk_row_idx=brow,
-            blk_col_idx=bcol,
-            nnz_per_blk=nnzb,
-            type_per_blk=typb,
-            vp_per_blk=vps,
-            packed=packed.packed,
-            colagg=agg,
-            balance_result=bal,
-            nnz=part.nnz,
-        )
 
     # ------------------------------------------------------------------
     # Planning — the autotune subsystem's entry points, surfaced here so

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import errors
+from repro import errors, obs
 
 from . import balance
 from .cb_matrix import CBMatrix
@@ -77,17 +77,30 @@ class ShardedStreams:
 
 def shard_streams(cb: CBMatrix, num_devices: int) -> ShardedStreams:
     """pq-balance CB blocks across devices and build uniform stacked streams."""
-    real = cb.nnz_per_blk > 0
-    real_idx = np.flatnonzero(real)
-    result = balance.device_load_balance(cb.nnz_per_blk[real_idx], num_devices)
+    with obs.span("cb.shard_streams", num_devices=num_devices):
+        real_idx = np.flatnonzero(cb.nnz_per_blk > 0)
+        with obs.span("cb.shard.balance"):
+            result = balance.device_load_balance(cb.nnz_per_blk[real_idx],
+                                                 num_devices)
+        per_dev: list[SpMVStreams] = []
+        gs = result.group_size
+        with obs.span("cb.shard.build"):
+            for d in range(num_devices):
+                slots = result.slots[d * gs : (d + 1) * gs]
+                blocks = real_idx[slots[slots >= 0]]
+                per_dev.append(build_streams(_sub_matrix(cb, blocks)))
+        with obs.span("cb.shard.stack"):
+            stacked = _stack_uniform(per_dev)
+        return ShardedStreams(
+            num_devices=num_devices,
+            streams=stacked,
+            device_nnz=result.group_loads.copy(),
+        )
 
-    per_dev: list[SpMVStreams] = []
-    for d in range(num_devices):
-        slots = result.slots[d * result.group_size : (d + 1) * result.group_size]
-        blocks = real_idx[slots[slots >= 0]]
-        sub = _sub_matrix(cb, blocks)
-        per_dev.append(build_streams(sub))
 
+def _stack_uniform(per_dev: list[SpMVStreams]) -> SpMVStreams:
+    """Stack per-device streams on a leading axis, each padded to the
+    largest block count and inner width of any device."""
     # Uniform shapes: pad block counts and inner pads to the per-axis max.
     nd = max(s.num_dense for s in per_dev)
     np_ = max(s.num_panel for s in per_dev)
@@ -111,15 +124,9 @@ def shard_streams(cb: CBMatrix, num_devices: int) -> ShardedStreams:
             coo_xidx=_pad_axis0(_pad_axis_last(np.asarray(s.coo_xidx), Ep), nc),
         )
 
-    padded = [pad(s) for s in per_dev]
-    stacked = jax.tree_util.tree_map(
-        lambda *xs: np.stack(xs), *padded
-    )
     # tree_map over dataclass keeps meta from the first element.
-    return ShardedStreams(
-        num_devices=num_devices,
-        streams=stacked,
-        device_nnz=result.group_loads.copy(),
+    return jax.tree_util.tree_map(
+        lambda *xs: np.stack(xs), *[pad(s) for s in per_dev]
     )
 
 

@@ -54,7 +54,7 @@ from . import column_agg as column_agg_mod
 from .aggregation import coord_bits
 from .cb_matrix import CBMatrix
 from .formats import FMT_COO, FMT_CSR, FMT_DENSE
-from repro import errors
+from repro import errors, obs
 
 # ---------------------------------------------------------------------------
 # Padding policy — the single place payload widths get aligned.
@@ -230,23 +230,26 @@ def _collect_blocks(cb: CBMatrix):
     bits = coord_bits(B)
     vdt = cb.val_dtype
     dense, panels, coos = [], [], []
-    for brow, bcol, fmt, r, c, v in cb.iter_blocks():
-        if fmt == FMT_DENSE:
-            tile = np.zeros((B, B), dtype=vdt)
-            tile[r, c] = v
-            dense.append((brow, tile, _block_x_indices(cb, brow, bcol), len(v)))
-        elif fmt == FMT_CSR:
-            ucols, rank = np.unique(c, return_inverse=True)
-            panel = np.zeros((B, len(ucols)), dtype=vdt)
-            panel[r, rank] = v
-            xidx = cb.global_x_index(brow, bcol, ucols).astype(np.int32)
-            panels.append((brow, panel, xidx))
-        elif fmt == FMT_COO:
-            codes = (c.astype(np.int64) << bits) | r.astype(np.int64)
-            xidx = cb.global_x_index(brow, bcol, c).astype(np.int32)
-            coos.append((brow, codes.astype(np.int32), v.astype(vdt), xidx))
-        else:  # pragma: no cover - format codes are exhaustive
-            raise errors.InvalidArgError(f"unknown format {fmt}")
+    with obs.span("cb.streams.collect"):
+        for brow, bcol, fmt, r, c, v in cb.iter_blocks():
+            if fmt == FMT_DENSE:
+                tile = np.zeros((B, B), dtype=vdt)
+                tile[r, c] = v
+                dense.append((brow, tile, _block_x_indices(cb, brow, bcol),
+                              len(v)))
+            elif fmt == FMT_CSR:
+                ucols, rank = np.unique(c, return_inverse=True)
+                panel = np.zeros((B, len(ucols)), dtype=vdt)
+                panel[r, rank] = v
+                xidx = cb.global_x_index(brow, bcol, ucols).astype(np.int32)
+                panels.append((brow, panel, xidx))
+            elif fmt == FMT_COO:
+                codes = (c.astype(np.int64) << bits) | r.astype(np.int64)
+                xidx = cb.global_x_index(brow, bcol, c).astype(np.int32)
+                coos.append((brow, codes.astype(np.int32), v.astype(vdt),
+                             xidx))
+            else:  # pragma: no cover - format codes are exhaustive
+                raise errors.InvalidArgError(f"unknown format {fmt}")
     return dense, panels, coos
 
 
@@ -449,22 +452,32 @@ def build_super_streams(
     allows.
     """
     B = cb.block_size
-    m, n = cb.shape
-    mb = -(-m // B)
-    vdt = cb.val_dtype
     G = group_size_for(B) if group_size is None else int(group_size)
     if G < 1:
         raise errors.InvalidArgError(f"group_size must be >= 1, got {G}")
+    with obs.span("cb.build_super_streams", group_size=G):
+        dense, panels, coos = _collect_blocks(cb)
+        with obs.span("cb.streams.layout"):
+            return _pack_super_streams(cb, G, dense, panels, coos)
 
-    dense, panels, coos = _collect_blocks(cb)
+
+def _pack_super_streams(cb: CBMatrix, G: int, dense, panels,
+                        coos) -> SuperBlockStreams:
+    """Lay ``_collect_blocks``' payloads out in balanced groups of ``G``
+    (``build_super_streams``)."""
+    B = cb.block_size
+    m, n = cb.shape
+    mb = -(-m // B)
+    vdt = cb.val_dtype
 
     # ---- dense: nnz-balanced tiles, evened slots per super-tile ---------
     nd = len(dense)
     if nd:
         _, Gd = even_group(nd, G)
-        bal = balance_mod.grid_group_balance(
-            np.asarray([e[3] for e in dense], np.int64), Gd
-        )
+        with obs.span("cb.streams.balance"):
+            bal = balance_mod.grid_group_balance(
+                np.asarray([e[3] for e in dense], np.int64), Gd
+            )
         gd = bal.num_groups
         d_tiles = np.zeros((gd, Gd * B, B), vdt)
         d_brow = np.zeros((gd, Gd), np.int32)
@@ -493,7 +506,9 @@ def build_super_streams(
         with a per-slot brow array of ``W // SUBLANE`` slots.
         """
         _, Gs = even_group(len(widths), G)
-        bal = balance_mod.grid_group_balance(np.asarray(widths, np.int64), Gs)
+        with obs.span("cb.streams.balance"):
+            bal = balance_mod.grid_group_balance(
+                np.asarray(widths, np.int64), Gs)
         ng = bal.num_groups
         slot_map = bal.slots.reshape(ng, Gs)
         W, offsets = lane_layout(widths, slot_map)

@@ -38,6 +38,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache  # noqa: F401 - counts cache hits/misses
 from repro import errors, obs
 from repro.core.streams import (
     LANE, SUBLANE, SpMVStreams, SuperBlockStreams, SuperTileStream,
@@ -111,6 +112,13 @@ def _regroup(streams: SpMVStreams, G: int) -> SuperBlockStreams:
     )
 
 
+def _gather(x: jax.Array, xidx: jax.Array, fmt: str) -> jax.Array:
+    """``x[xidx]``, named ``cb_gather/<fmt>`` in the HLO's ``op_name``
+    metadata so a profile finds the gather by name."""
+    with jax.named_scope("cb_gather"), jax.named_scope(fmt):
+        return x[xidx]
+
+
 def _super_partials_pallas(s: SuperBlockStreams, x: jax.Array, interp: bool):
     """One pallas_call per present format -> [(partials (t, B), brow (t,))].
 
@@ -121,17 +129,17 @@ def _super_partials_pallas(s: SuperBlockStreams, x: jax.Array, interp: bool):
     parts = []
     if s.num_dense_groups:
         part = cb_block_dense.block_dense_spmv_batched(
-            s.dense_tiles, x[s.dense_xidx], interpret=interp
+            s.dense_tiles, _gather(x, s.dense_xidx, "dense"), interpret=interp
         )
         parts.append((part.reshape(-1, B), s.dense_brow.reshape(-1)))
     if s.num_panel_groups:
         part = cb_colagg.panel_spmv_batched(
-            s.panel_vals, x[s.panel_xidx], interpret=interp,
+            s.panel_vals, _gather(x, s.panel_xidx, "panel"), interpret=interp,
         )
         parts.append((part.reshape(-1, B), s.panel_brow.reshape(-1)))
     if s.num_coo_groups:
         part = cb_coo.coo_spmv_batched(
-            s.coo_codes, s.coo_vals, x[s.coo_xidx],
+            s.coo_codes, s.coo_vals, _gather(x, s.coo_xidx, "coo"),
             block_size=B, interpret=interp,
         )
         parts.append((part.reshape(-1, B), s.coo_brow.reshape(-1)))
@@ -242,7 +250,7 @@ def spmm_launch_stats(
     }
 
 
-def _record_call(entry: str, stats: dict, impl: str, plan) -> None:
+def _record_call(entry: str, stats: dict, impl: str) -> None:
     """Emit one call's launch accounting to the default registry.
 
     Runs outside jitted code — under an outer ``jax.jit`` this is a
@@ -263,19 +271,6 @@ def _record_call(entry: str, stats: dict, impl: str, plan) -> None:
             steps.inc(n, format=fmt)
             padded.inc(stats["padded"][fmt], format=fmt)
     reg.gauge(f"repro.ops.{entry}.group_size").set(stats["group_size"])
-    if plan is not None and entry in ("spmv", "spmv_into"):
-        # measured-vs-predicted per plan: the raw material for online
-        # calibration of the cost model (ROADMAP) — both sides accumulate
-        # once per call, so their ratio is the per-call fidelity.
-        label = plan.structure_hash[:12]
-        exec_padded = reg.counter("repro.autotune.exec.padded_elems")
-        exec_steps = reg.counter("repro.autotune.exec.steps")
-        reg.counter("repro.autotune.exec.calls").inc(plan=label)
-        exec_padded.inc(stats["padded_total"], plan=label, kind="measured")
-        exec_padded.inc(plan.predicted_padded_elems, plan=label,
-                        kind="predicted")
-        exec_steps.inc(stats["steps_total"], plan=label, kind="measured")
-        exec_steps.inc(plan.predicted_steps, plan=label, kind="predicted")
 
 
 @functools.partial(
@@ -341,7 +336,7 @@ def cb_spmv(
     if obs.is_enabled():
         g = group_size if group_size is not None else (
             plan.group_size if plan is not None else None)
-        _record_call("spmv", spmv_launch_stats(streams, g), impl, plan)
+        _record_call("spmv", spmv_launch_stats(streams, g), impl)
     return y
 
 
@@ -362,9 +357,10 @@ def _combine_into(y2d, sup: SuperBlockStreams, x: jax.Array, interp: bool):
     parts = _super_partials_pallas(sup, x, interp)
     if parts:
         # ONE fused scatter-add over every format's per-slot partials.
-        all_parts = jnp.concatenate([p for p, _ in parts], axis=0)
-        all_brow = jnp.concatenate([b for _, b in parts], axis=0)
-        y2d = y2d.at[all_brow].add(all_parts)
+        with jax.named_scope("cb_combine"):
+            all_parts = jnp.concatenate([p for p, _ in parts], axis=0)
+            all_brow = jnp.concatenate([b for _, b in parts], axis=0)
+            y2d = y2d.at[all_brow].add(all_parts)
     return y2d
 
 
@@ -427,7 +423,7 @@ def cb_spmv_into(
     if obs.is_enabled():
         g = group_size if group_size is not None else (
             plan.group_size if plan is not None else None)
-        _record_call("spmv_into", spmv_launch_stats(streams, g), impl, plan)
+        _record_call("spmv_into", spmv_launch_stats(streams, g), impl)
     return y
 
 
@@ -542,6 +538,6 @@ def cb_spmm(
         _record_call(
             "spmm",
             spmm_launch_stats(stream, g, n_cols=n_cols, block_n=block_n),
-            impl, plan,
+            impl,
         )
     return Y

@@ -8,22 +8,31 @@ injectable monotonic clock (``metrics.now``):
         sp.set(status="ok")
 
 Spans nest per thread (a thread-local stack records each span's depth
-and parent), cost two clock reads plus one list append, and become
-no-ops when obs is disabled. Completed spans accumulate in a bounded
-in-process buffer on the :class:`Tracer`; ``chrome_trace()`` renders
-them as Chrome ``trace_event`` *complete* events (``ph: "X"``, µs
-timestamps relative to the tracer epoch) — load the exported
-``.trace.json`` in ``chrome://tracing`` / Perfetto, or feed it to
-``scripts/obs_report.py`` for a terminal summary.
+and the id of its parent, the span open around it), cost two clock
+reads plus one list append, and become no-ops when obs is disabled.
+Completed spans accumulate in a bounded in-process buffer on the
+:class:`Tracer`; ``summary()`` gives each name's total and self time
+(its duration less what its direct children cover), and
+``chrome_trace()`` renders them as Chrome ``trace_event`` *complete*
+events (``ph: "X"``, µs timestamps relative to the tracer epoch) — load
+the exported ``.trace.json`` in ``chrome://tracing`` / Perfetto, or feed
+it to ``scripts/obs_report.py`` for a terminal summary.
 
-Determinism: timestamps come only from the configured clock and thread
-ids are logical (0, 1, ... in first-seen order, not OS idents), so a
-fake clock reproduces byte-identical traces.
+Where ``jax`` is already imported, each span also enters a
+``jax.profiler.TraceAnnotation`` of its name, so a profile taken around
+it shows the span on its host plane, on the device trace's clock. This
+module never imports jax itself.
+
+Determinism: timestamps come only from the configured clock, and thread
+and span ids are logical (0, 1, ... in first-seen order, not OS idents),
+so a fake clock reproduces byte-identical traces.
 """
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import json
+import sys
 import threading
 
 from . import metrics
@@ -39,6 +48,8 @@ class SpanRecord:
     depth: int
     tid: int
     attrs: dict
+    span_id: int = 0
+    parent: int | None = None   # span_id of the enclosing span; None at a root
 
 
 class _NullSpan:
@@ -62,7 +73,8 @@ _NULL_SPAN = _NullSpan()
 class Span:
     """Context manager for one traced region; ``set()`` adds attrs."""
 
-    __slots__ = ("name", "attrs", "_tracer", "_start", "_depth", "_tid")
+    __slots__ = ("name", "attrs", "_tracer", "_start", "_depth", "_tid",
+                 "_id", "_parent", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict) -> None:
         self.name = name
@@ -71,6 +83,9 @@ class Span:
         self._start = 0.0
         self._depth = 0
         self._tid = 0
+        self._id = 0
+        self._parent = None
+        self._annotation = None
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
@@ -79,12 +94,21 @@ class Span:
     def __enter__(self) -> "Span":
         self._tid, stack = self._tracer._thread_state()
         self._depth = len(stack)
+        self._parent = stack[-1]._id if stack else None
+        self._id = next(self._tracer._ids)
         stack.append(self)
+        profiler = sys.modules.get("jax.profiler")
+        if profiler is not None:
+            self._annotation = profiler.TraceAnnotation(self.name)
+            self._annotation.__enter__()
         self._start = metrics.now()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         end = metrics.now()
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         _, stack = self._tracer._thread_state()
         if stack and stack[-1] is self:
             stack.pop()
@@ -97,6 +121,8 @@ class Span:
             depth=self._depth,
             tid=self._tid,
             attrs=dict(self.attrs),
+            span_id=self._id,
+            parent=self._parent,
         ))
         return False
 
@@ -111,6 +137,7 @@ class Tracer:
         self._records: list[SpanRecord] = []
         self._tids: dict[int, int] = {}
         self._epoch: float | None = None
+        self._ids = itertools.count()
         self.dropped = 0
 
     # -- recording ------------------------------------------------------
@@ -149,22 +176,30 @@ class Tracer:
             self._records.clear()
             self._tids.clear()
             self._epoch = None
+            self._ids = itertools.count()
             self.dropped = 0
         self._local = threading.local()
 
     def summary(self) -> list[dict]:
-        """Per-name aggregate rows (count, total/mean/max seconds),
+        """Per-name aggregate rows (count, total/mean/max seconds, and
+        ``self_s``: the total less the time its direct children cover),
         sorted by total descending — the obs_report table."""
+        records = self.records()
+        children: dict[int, float] = {}
+        for r in records:
+            if r.parent is not None:
+                children[r.parent] = children.get(r.parent, 0.0) + r.duration
         agg: dict[str, list] = {}
-        for r in self.records():
-            row = agg.setdefault(r.name, [0, 0.0, 0.0])
+        for r in records:
+            row = agg.setdefault(r.name, [0, 0.0, 0.0, 0.0])
             row[0] += 1
             row[1] += r.duration
             row[2] = max(row[2], r.duration)
+            row[3] += r.duration - children.get(r.span_id, 0.0)
         return [
             {"name": name, "count": c, "total_s": tot,
-             "mean_s": tot / c, "max_s": mx}
-            for name, (c, tot, mx) in sorted(
+             "mean_s": tot / c, "max_s": mx, "self_s": own}
+            for name, (c, tot, mx, own) in sorted(
                 agg.items(), key=lambda kv: -kv[1][1])
         ]
 
@@ -180,7 +215,8 @@ class Tracer:
                 "dur": r.duration * 1e6,
                 "pid": 1,
                 "tid": r.tid,
-                "args": dict(r.attrs, depth=r.depth),
+                "args": dict(r.attrs, depth=r.depth, id=r.span_id,
+                             parent=r.parent),
             }
             for r in self.records()
         ]

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.cb_matrix import CBMatrix
 
 
@@ -130,8 +131,9 @@ def jacobi(cb: CBMatrix) -> JacobiPreconditioner:
 
 def block_jacobi(cb: CBMatrix) -> BlockJacobiPreconditioner:
     """Block-Jacobi from the materialized CB diagonal tiles."""
-    return _block_jacobi_from_diag(_diag_blocks(cb), cb.shape[0],
-                                   cb.block_size)
+    with obs.span("cb.block_jacobi"):
+        return _block_jacobi_from_diag(_diag_blocks(cb), cb.shape[0],
+                                       cb.block_size)
 
 
 # ---------------------------------------------------------------------------

@@ -167,3 +167,42 @@ def test_distributed_spmv_compiles_on_four_chips(topo):
             interpret=False)
 
     assert "cb_coo_spmv_batched" in _kernels(fn, streams, x)
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_tpu_fusions_keep_the_gather_and_combine_scopes(topo, chips):
+    """After the TPU compiler fuses them, the x gathers and the scatter-add
+    still carry ``cb_gather/<format>`` and ``cb_combine`` in their
+    ``op_name``: what a profile reduction maps device events by."""
+    m = n = 1024
+    r, c, v = matrices.power_law(m, n, seed=0)
+    cb = CBMatrix.from_coo(r, c, v, (m, n), block_size=16)
+    if chips == 1:
+        one = SingleDeviceSharding(topo.devices[0])
+        args = (_specs_of(build_super_streams(cb), one),
+                _spec((n,), jnp.float32, one))
+
+        def fn(st, xx):
+            return ops._cb_spmv_jit(st, xx, interpret=False)
+    else:
+        sharded = dist.shard_streams(cb, 4)
+        mesh = compat.make_mesh((4,), ("model",), devices=topo.devices[:4])
+        args = (_specs_of(sharded.streams, NamedSharding(mesh, P("model"))),
+                _spec((n,), jnp.float32, NamedSharding(mesh, P())))
+
+        def fn(st, xx):
+            return dist.distributed_spmv(
+                dist.ShardedStreams(4, st, sharded.device_nnz), xx, mesh,
+                interpret=False)
+
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    scopes = {}
+    for line in text.splitlines():
+        hit = re.search(r'= \S+ (gather|scatter|fusion)\(.*op_name="([^"]*)"',
+                        line)
+        if hit:
+            scopes.setdefault(hit.group(1), set()).add(hit.group(2))
+    fused = scopes.get("fusion", set())
+    for fmt in ("coo", "panel"):
+        assert any(f"/cb_gather/{fmt}/gather" in o for o in fused), fused
+    assert any("/cb_combine/scatter-add" in o for o in fused), fused
