@@ -251,14 +251,14 @@ def distributed_phase(coo, shape, devices):
     cb = CBMatrix.from_coo(*coo, shape, block_size=16)
     sharded = dist.shard_streams(cb, len(devices))
     setup_s = time.perf_counter() - t0
-    flat = sharded.streams
-    nbytes = sum(a.nbytes for a in jax.tree_util.tree_leaves(flat))
-    # one grid step per block on each device (flat streams, no packing)
-    blocks = ops.spmv_launch_stats(
-        jax.tree_util.tree_map(lambda a: a[0], flat))["steps"]
+    stacked = sharded.streams
+    nbytes = sum(a.nbytes for a in jax.tree_util.tree_leaves(stacked))
+    # packed groups (grid steps) per device, the same on every device
+    groups = ops.spmv_launch_stats(
+        jax.tree_util.tree_map(lambda a: a[0], stacked))["steps"]
     report("distributed.streams", matrix=f"{shape[0]}x{shape[1]}",
-           nnz=cb.nnz, devices=len(devices), blocks_per_device=blocks,
-           coo_width=flat.coo_codes.shape[2], hbm_bytes_total=nbytes,
+           nnz=cb.nnz, devices=len(devices), groups_per_device=groups,
+           coo_width=stacked.coo_codes.shape[2], hbm_bytes_total=nbytes,
            hbm_bytes_per_device=nbytes // len(devices),
            device_nnz=sharded.device_nnz.tolist(), setup_s=setup_s)
     mesh = compat.make_mesh((len(devices),), ("model",), devices=devices)
@@ -274,7 +274,7 @@ def distributed_phase(coo, shape, devices):
     y_ref = scipy_of(coo, shape) @ np.asarray(x, np.float64)
     err = require_close("distributed", y, y_ref)
     compile_s = check_kernels(run, placed.streams, x,
-                              expect=present_kernels(blocks))
+                              expect=present_kernels(groups))
     y_one = ops.cb_spmv(build_super_streams(cb).device_put(), x)
     err_one = require_close("distributed.vs_single", y,
                             np.asarray(y_one, np.float64))

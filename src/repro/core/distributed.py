@@ -7,12 +7,16 @@ count per device gives uniform shard shapes (a shard_map requirement) and
 near-equal nnz gives near-equal work — the straggler story at mesh scale.
 
 Pipeline:
-  1. ``shard_streams``   (host) — pq-assign blocks to devices, build one
-     SpMVStreams per device, pad every stream to the max per-device shape
-     with zero blocks, stack into leading-axis-``D`` arrays.
+  1. ``shard_streams``   (host) — pq-assign blocks to devices, pack each
+     device's blocks into SuperBlockStreams with ``build_super_streams``
+     (the one-chip packer, default group size, so every device has the
+     same ``G``), pad every device to the largest group count, slot count
+     and lane width with zero groups, slots and lanes, stack into
+     leading-axis-``D`` arrays.
   2. ``distributed_spmv`` — shard_map over the model axis: each device
-     runs the single-device kernels on its shard against a replicated x,
-     then a single ``psum`` (or ``psum_scatter``) combines partial y.
+     runs the single-device batched kernels on its shard against a
+     replicated x, then a single ``psum`` (or ``psum_scatter``) combines
+     partial y.
 
 x stays replicated (SpMV x is tiny relative to the matrix); y combine is
 one collective — the communication-minimal schedule for 1D row-partitioned
@@ -31,29 +35,28 @@ from repro import errors, obs
 
 from . import balance
 from .cb_matrix import CBMatrix
-from .streams import SpMVStreams, build_streams
+from .streams import SuperBlockStreams, build_super_streams
 
 
-def _pad_axis0(arr: np.ndarray, target: int) -> np.ndarray:
-    if arr.shape[0] == target:
-        return arr
-    pad = np.zeros((target - arr.shape[0],) + arr.shape[1:], arr.dtype)
-    return np.concatenate([arr, pad], axis=0)
-
-
-def _pad_axis_last(arr: np.ndarray, target: int) -> np.ndarray:
-    if arr.shape[-1] == target:
-        return arr
-    widths = [(0, 0)] * (arr.ndim - 1) + [(0, target - arr.shape[-1])]
-    return np.pad(arr, widths)
+def _pad_to(arr: np.ndarray, shape: tuple) -> np.ndarray:
+    """``arr`` in the leading corner of a zero array of ``shape``."""
+    out = np.zeros(shape, arr.dtype)
+    if arr.size:
+        out[tuple(map(slice, arr.shape))] = arr
+    return out
 
 
 @dataclasses.dataclass
 class ShardedStreams:
-    """Per-device SpMV streams stacked on a leading device axis."""
+    """Per-device packed streams stacked on a leading device axis.
+
+    ``streams`` is one ``SuperBlockStreams`` whose every array has a
+    leading dim ``D``: slice ``d`` is device ``d``'s packed groups, padded
+    to the shape of the largest device.
+    """
 
     num_devices: int
-    streams: SpMVStreams      # every array has leading dim D
+    streams: SuperBlockStreams
     device_nnz: np.ndarray    # (D,) achieved nnz per device (diagnostics)
 
     @property
@@ -76,19 +79,20 @@ class ShardedStreams:
 
 
 def shard_streams(cb: CBMatrix, num_devices: int) -> ShardedStreams:
-    """pq-balance CB blocks across devices and build uniform stacked streams."""
+    """pq-balance CB blocks across devices and build uniform stacked
+    packed streams."""
     with obs.span("cb.shard_streams", num_devices=num_devices):
         real_idx = np.flatnonzero(cb.nnz_per_blk > 0)
         with obs.span("cb.shard.balance"):
             result = balance.device_load_balance(cb.nnz_per_blk[real_idx],
                                                  num_devices)
-        per_dev: list[SpMVStreams] = []
+        per_dev: list[SuperBlockStreams] = []
         gs = result.group_size
         with obs.span("cb.shard.build"):
             for d in range(num_devices):
                 slots = result.slots[d * gs : (d + 1) * gs]
                 blocks = real_idx[slots[slots >= 0]]
-                per_dev.append(build_streams(_sub_matrix(cb, blocks)))
+                per_dev.append(build_super_streams(_sub_matrix(cb, blocks)))
         with obs.span("cb.shard.stack"):
             stacked = _stack_uniform(per_dev)
         return ShardedStreams(
@@ -98,36 +102,23 @@ def shard_streams(cb: CBMatrix, num_devices: int) -> ShardedStreams:
         )
 
 
-def _stack_uniform(per_dev: list[SpMVStreams]) -> SpMVStreams:
-    """Stack per-device streams on a leading axis, each padded to the
-    largest block count and inner width of any device."""
-    # Uniform shapes: pad block counts and inner pads to the per-axis max.
-    nd = max(s.num_dense for s in per_dev)
-    np_ = max(s.num_panel for s in per_dev)
-    nc = max(s.num_coo for s in per_dev)
-    Kp = max(s.panel_vals.shape[2] for s in per_dev)
-    Ep = max(s.coo_codes.shape[1] for s in per_dev)
+def _stack_uniform(per_dev: list[SuperBlockStreams]) -> SuperBlockStreams:
+    """Stack per-device packed streams on a leading axis, each array padded
+    to the largest group count, slot count and lane width of any device.
 
-    def pad(s: SpMVStreams) -> SpMVStreams:
-        return SpMVStreams(
-            block_size=s.block_size, m=s.m, n=s.n, mb=s.mb,
-            colagg_applied=s.colagg_applied,
-            dense_tiles=_pad_axis0(np.asarray(s.dense_tiles), nd),
-            dense_brow=_pad_axis0(np.asarray(s.dense_brow), nd),
-            dense_xidx=_pad_axis0(np.asarray(s.dense_xidx), nd),
-            panel_vals=_pad_axis0(_pad_axis_last(np.asarray(s.panel_vals), Kp), np_),
-            panel_brow=_pad_axis0(np.asarray(s.panel_brow), np_),
-            panel_xidx=_pad_axis0(_pad_axis_last(np.asarray(s.panel_xidx), Kp), np_),
-            coo_codes=_pad_axis0(_pad_axis_last(np.asarray(s.coo_codes), Ep), nc),
-            coo_vals=_pad_axis0(_pad_axis_last(np.asarray(s.coo_vals), Ep), nc),
-            coo_brow=_pad_axis0(np.asarray(s.coo_brow), nc),
-            coo_xidx=_pad_axis0(_pad_axis_last(np.asarray(s.coo_xidx), Ep), nc),
-        )
+    Padding groups, slots and lanes hold zero values, x index 0 and brow
+    0, as the packer's own padding does, so they scatter-add exact zeros.
+    A device with no groups of a format does not set that format's inner
+    shape (an empty dense stream still carries ``G`` slots).
+    """
+    def stack(*arrs):
+        full = [a for a in arrs if a.shape[0]] or arrs[:1]
+        shape = (max(a.shape[0] for a in arrs),
+                 *np.max([a.shape[1:] for a in full], axis=0))
+        return np.stack([_pad_to(a, shape) for a in arrs])
 
-    # tree_map over dataclass keeps meta from the first element.
-    return jax.tree_util.tree_map(
-        lambda *xs: np.stack(xs), *[pad(s) for s in per_dev]
-    )
+    # tree_map over the dataclass keeps the (shared) meta of the first.
+    return jax.tree_util.tree_map(stack, *per_dev)
 
 
 def _sub_matrix(cb: CBMatrix, block_slots: np.ndarray) -> CBMatrix:

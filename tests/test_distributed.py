@@ -2,9 +2,11 @@
 xla_force_host_platform_device_count (the main test process must keep the
 default single CPU device — see the dry-run contract).
 """
+import inspect
 import os
 import subprocess
 import sys
+import textwrap
 
 import pytest
 
@@ -25,8 +27,88 @@ def _run(code: str, devices: int = 4) -> str:
     return out.stdout
 
 
-def test_distributed_spmv_4dev():
-    _run("""
+def _mixed_power_law(m=256, n=256, seed=7):
+    """A power-law matrix plus two full 16x16 blocks: dense-format blocks
+    that land on some devices only, so the stacked shards differ per
+    device in every format's group count or width."""
+    import numpy as np
+
+    from repro.data import matrices
+
+    r, c, v = matrices.power_law(m, n, seed=seed)
+    br, bc = (a.ravel() for a in np.meshgrid(np.arange(16), np.arange(16),
+                                            indexing="ij"))
+    r = np.concatenate([r, br + 32, br + 128])
+    c = np.concatenate([c, bc + 200, bc + 64])
+    v = np.concatenate([v, np.ones(2 * br.size, v.dtype)])
+    _, keep = np.unique(r * n + c, return_index=True)
+    return r[keep], c[keep], v[keep], (m, n)
+
+
+def test_shard_streams_stacks_each_devices_packed_streams():
+    """Every device's shard is ``build_super_streams`` of its own blocks,
+    zero-padded to the largest device, and gathers no more lanes than the
+    flat one-block-per-step layout would."""
+    import numpy as np
+
+    from repro.core import balance
+    from repro.core import distributed as dist
+    from repro.core.cb_matrix import CBMatrix
+    from repro.core.streams import (SuperBlockStreams, build_streams,
+                                    build_super_streams)
+
+    cb = CBMatrix.from_coo(*_mixed_power_law(), block_size=16,
+                           val_dtype=np.float32)
+    D = 4
+    sh = dist.shard_streams(cb, D)
+    st = sh.streams
+    assert isinstance(st, SuperBlockStreams)
+    real = np.flatnonzero(cb.nnz_per_blk > 0)
+    res = balance.device_load_balance(cb.nnz_per_blk[real], D)
+    gs = res.group_size
+    own, flat = [], []
+    for d in range(D):
+        slots = res.slots[d * gs : (d + 1) * gs]
+        sub = dist._sub_matrix(cb, real[slots[slots >= 0]])
+        own.append(build_super_streams(sub))
+        flat.append(build_streams(sub))
+    assert {s.group_size for s in own} == {st.group_size}
+    # the shards differ per device, so the padding is exercised
+    assert len({s.num_dense_groups for s in own}) > 1
+    assert len({s.panel_vals.shape[-1] for s in own}) > 1
+    for d, s in enumerate(own):
+        for name, a in vars(s).items():
+            if not isinstance(a, np.ndarray):
+                continue
+            got = np.asarray(getattr(st, name))[d]
+            assert got.shape[0] == max(getattr(o, name).shape[0] for o in own)
+            rest = got.copy()
+            if a.shape[0]:  # an empty format sets no inner shape
+                assert all(g >= w for g, w in zip(got.shape, a.shape)), name
+                corner = tuple(map(slice, a.shape))
+                np.testing.assert_array_equal(got[corner], a, err_msg=name)
+                rest[corner] = 0
+            assert not rest.any(), name  # padding is zeros alone
+    # the stacked widths are the largest device's, not wider
+    for name in ("dense_tiles", "panel_vals", "coo_codes"):
+        full = [getattr(s, name) for s in own if getattr(s, name).shape[0]]
+        assert getattr(st, name).shape[2:] == max(a.shape[1:] for a in full)
+    # gathered lanes per device: packed layout against the flat one
+    packed = sum(np.asarray(a)[0].size for a in
+                 (st.dense_xidx, st.panel_xidx, st.coo_xidx))
+    flat_lanes = (max(f.num_dense for f in flat) * 16
+                  + max(f.num_panel for f in flat)
+                  * max(f.panel_xidx.shape[1] for f in flat)
+                  + max(f.num_coo for f in flat)
+                  * max(f.coo_xidx.shape[1] for f in flat))
+    assert packed <= flat_lanes, (packed, flat_lanes)
+
+
+@pytest.mark.parametrize("impl", ["pallas", "reference"])
+def test_distributed_spmv_4dev(impl):
+    """Both engines against the dense oracle, under both combines, on a
+    divisible and a ragged ``m`` and on shards that differ per device."""
+    _run(textwrap.dedent(inspect.getsource(_mixed_power_law)) + f"""
 import numpy as np, jax, jax.numpy as jnp
 from repro import compat
 from repro.core.cb_matrix import CBMatrix
@@ -34,24 +116,29 @@ from repro.core import distributed as dist
 from repro.core.spmv_ref import dense_oracle
 from repro.data import matrices
 
-m, n = 160, 160
-r, c, v = matrices.power_law(m, n, seed=7)
-cb = CBMatrix.from_coo(r, c, v, (m, n), block_size=16, val_dtype=np.float32)
-sh = dist.shard_streams(cb, 4)
-assert sh.load_imbalance < 1.2, sh.device_nnz
 mesh = compat.make_mesh((4,), ("model",))
-x = np.random.default_rng(0).standard_normal(n).astype(np.float32)
-y0 = dense_oracle(r, c, v.astype(np.float32), (m, n), x)
-for impl in ("pallas", "reference"):
-    y = dist.distributed_spmv(sh, jnp.asarray(x), mesh, impl=impl, interpret=True)
-    np.testing.assert_allclose(np.asarray(y), y0, rtol=3e-4, atol=3e-4)
+cases = [matrices.power_law(m, n, seed=7) + ((m, n),)
+         for m, n in ((160, 160), (150, 144))]
+cases.append(_mixed_power_law())
+for r, c, v, (m, n) in cases:
+    cb = CBMatrix.from_coo(r, c, v, (m, n), block_size=16,
+                           val_dtype=np.float32)
+    sh = dist.shard_streams(cb, 4)
+    assert sh.load_imbalance < 1.2, sh.device_nnz
+    x = np.random.default_rng(0).standard_normal(n).astype(np.float32)
+    y0 = dense_oracle(r, c, v.astype(np.float32), (m, n), x)
+    for combine in ("psum", "psum_scatter"):
+        y = dist.distributed_spmv(sh, jnp.asarray(x), mesh, impl={impl!r},
+                                  interpret=True, combine=combine)
+        assert y.shape == (m,), (combine, y.shape)
+        np.testing.assert_allclose(np.asarray(y), y0, rtol=3e-4, atol=3e-4)
 print("OK")
 """)
 
 
 def test_distributed_spmv_combine_modes():
-    """psum_scatter (sharded y) and legacy psum agree with the oracle; an
-    axis-divisible m keeps the scatter output sharded end to end."""
+    """psum_scatter keeps an axis-divisible m's output sharded end to end;
+    an unknown combine is refused."""
     _run("""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
@@ -62,19 +149,15 @@ from repro.core.spmv_ref import dense_oracle
 from repro.data import matrices
 
 mesh = compat.make_mesh((4,), ("model",))
-for m, n in ((160, 160), (150, 144)):  # divisible / ragged over D=4
-    r, c, v = matrices.power_law(m, n, seed=7)
-    cb = CBMatrix.from_coo(r, c, v, (m, n), block_size=16, val_dtype=np.float32)
-    sh = dist.shard_streams(cb, 4)
-    x = np.random.default_rng(0).standard_normal(n).astype(np.float32)
-    y0 = dense_oracle(r, c, v.astype(np.float32), (m, n), x)
-    for combine in ("psum", "psum_scatter"):
-        y = dist.distributed_spmv(sh, jnp.asarray(x), mesh, impl="reference",
-                                  combine=combine)
-        assert y.shape == (m,), (combine, y.shape)
-        np.testing.assert_allclose(np.asarray(y), y0, rtol=3e-4, atol=3e-4)
-        if combine == "psum_scatter" and m % 4 == 0:
-            assert y.sharding.spec == P("model"), y.sharding
+m = n = 160
+r, c, v = matrices.power_law(m, n, seed=7)
+cb = CBMatrix.from_coo(r, c, v, (m, n), block_size=16, val_dtype=np.float32)
+sh = dist.shard_streams(cb, 4)
+x = np.random.default_rng(0).standard_normal(n).astype(np.float32)
+y0 = dense_oracle(r, c, v.astype(np.float32), (m, n), x)
+y = dist.distributed_spmv(sh, jnp.asarray(x), mesh, impl="reference")
+np.testing.assert_allclose(np.asarray(y), y0, rtol=3e-4, atol=3e-4)
+assert y.sharding.spec == P("model"), y.sharding
 try:
     dist.distributed_spmv(sh, jnp.asarray(x), mesh, combine="bogus")
 except ValueError:

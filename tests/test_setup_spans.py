@@ -61,7 +61,10 @@ ENTRY_POINTS = {
         {"cb.shard_streams": None,
          "cb.shard.balance": "cb.shard_streams",
          "cb.shard.build": "cb.shard_streams",
-         "cb.streams.collect": "cb.shard.build",
+         "cb.build_super_streams": "cb.shard.build",
+         "cb.streams.collect": "cb.build_super_streams",
+         "cb.streams.layout": "cb.build_super_streams",
+         "cb.streams.balance": "cb.streams.layout",
          "cb.shard.stack": "cb.shard_streams"}),
     "block_jacobi": (
         lambda cb: block_jacobi(cb),
