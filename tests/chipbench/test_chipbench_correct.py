@@ -20,21 +20,19 @@ from chipbench import harness  # noqa: E402
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 CELLS = {w["name"]: w for w in BENCH["workloads"]}
 
-# The faults each operation can have.
-FAULTS = {
-    "spmv": ["altered", "half"],
-    "cg": ["unchanged", "altered", "half"],
-    "dist_spmv": ["no_exchange", "altered", "half"],
-}
+OPS = sorted({chipbench_faults.op_of(w) for w in CELLS})
+CASES = [(w, f) for w in CELLS
+         for f in chipbench_faults.fault_names(chipbench_faults.op_of(w))]
 
 
-def op_of(workload):
-    traffic = json.loads((ROOT / "chipbench" / "traffic" /
-                          f"{CELLS[workload]['traffic']}.json").read_text())
-    return traffic["op"]
-
-
-CASES = [(w, f) for w in CELLS for f in FAULTS[op_of(w)]]
+@pytest.mark.parametrize("op", OPS)
+def test_every_op_has_checks(op):
+    """Each operation a cell runs has its faults and traced metrics."""
+    path = chipbench_faults.checks_path(op)
+    assert path.is_file(), f"operation {op!r} has no checks file {path}"
+    checks = chipbench_faults.load_checks(op)
+    assert checks.FAULTS and all(callable(f) for f in checks.FAULTS.values())
+    assert checks.TRACED
 
 
 @pytest.mark.parametrize("workload,fault", CASES)

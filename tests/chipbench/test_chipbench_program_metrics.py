@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent))
+import chipbench_faults  # noqa: E402
 import chipbench_tiny  # noqa: E402
 from chipbench_tiny import ROOT  # noqa: E402
 
@@ -70,8 +71,11 @@ def test_four_device_traced_run_reports_the_program_metrics(tmp_path):
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]
-    check_program_metrics(json.loads(out.stdout.strip().splitlines()[-1]),
-                          "g500-s19.spmv.4chip")
+    metrics = json.loads(out.stdout.strip().splitlines()[-1])
+    check_program_metrics(metrics, "g500-s19.spmv.4chip")
+    checks = chipbench_faults.load_checks(
+        chipbench_faults.op_of("g500-s19.spmv.4chip"))
+    assert set(checks.TRACED) <= set(metrics)
 
 
 def test_program_metrics_read_nothing_where_the_program_records_nothing(

@@ -1,8 +1,9 @@
 """Whole runs of tiny cells on the CPU: the result line, the refusals, and
-a cell added as files plus one ``workloads`` entry."""
+a configuration, operation and cell added as files plus their entries."""
 import json
 import os
 import pathlib
+import re
 import shutil
 import subprocess
 import sys
@@ -10,6 +11,7 @@ import sys
 import pytest
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent))
+import chipbench_faults  # noqa: E402
 import chipbench_tiny  # noqa: E402
 from chipbench_tiny import ROOT  # noqa: E402
 
@@ -66,8 +68,8 @@ def test_tiny_cell_runs_and_prints_the_contract_line(tiny, workload, trace):
     if not trace:
         assert {"setup_s", "op_ms"} <= set(r["metrics"])
     else:
-        assert {"setup.from_coo_s", "setup.pack_s", "setup.compile_s",
-                "launch.grid_steps"} <= set(r["metrics"])
+        checks = chipbench_faults.load_checks(chipbench_faults.op_of(workload))
+        assert set(checks.TRACED) <= set(r["metrics"])
     json.dumps(r)
 
 
@@ -173,6 +175,79 @@ def test_a_new_cell_is_files_plus_one_workloads_entry(tiny):
     r = harness.run_cell("er-tiny.spmv", 3, 0.2, False, root=tiny,
                          require_tpu=False, cache=False)
     check_line(r, "er-tiny.spmv", 0, root=tiny)
+
+
+# A configuration, generator, traffic mix, operation and cell, with their
+# tiny sizes and checks, laid out as in the repository, and their entries.
+EXAMPLE = pathlib.Path(__file__).resolve().parent / "example_cell"
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in BENCH["configs"]])
+def test_every_config_has_tiny_sizes(config):
+    """Each configuration has tiny sizes, and they name only its own keys."""
+    path = chipbench_tiny.tiny_path(config)
+    assert path.is_file(), f"configuration {config!r} has no tiny sizes file {path}"
+    entry = {c["name"]: c for c in BENCH["configs"]}[config]
+    keys = json.loads((ROOT / entry["file"]).read_text())
+    assert set(json.loads(path.read_text())) <= set(keys)
+
+
+def test_a_new_config_op_and_cell_need_no_edit(tmp_path):
+    """On a copy of the benchmark and its tests, a new generator,
+    configuration, traffic mix, operation and cell, with their tiny sizes
+    and checks, join as new files plus one ``configs`` and one
+    ``workloads`` entry: the tests collect them, the cell's tiny run is
+    correct and each of its faults makes it not correct."""
+    copy = tmp_path / "repo"
+    copy.mkdir()
+    shutil.copy(ROOT / "BENCHMARK.json", copy)
+    for p in ("chipbench", "tests/chipbench"):
+        shutil.copytree(ROOT / p, copy / p,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONDONTWRITEBYTECODE="1",
+               PYTHONPATH=str(ROOT / "src"))
+    env.pop("XLA_FLAGS", None)
+
+    def collected():
+        out = subprocess.run(
+            [sys.executable, "-m", "pytest", "--collect-only", "-q",
+             "-p", "no:cacheprovider", "tests/chipbench"],
+            cwd=copy, env=env, capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stdout[-3000:] + out.stderr[-3000:]
+        return int(re.search(r"(\d+) tests? collected", out.stdout).group(1))
+
+    before_count = collected()
+    before = {p: p.read_bytes() for p in copy.rglob("*") if p.is_file()}
+    for src in EXAMPLE.rglob("*"):
+        if (src.is_file() and src.name != "entries.json"
+                and "__pycache__" not in src.parts):
+            dst = copy / src.relative_to(EXAMPLE)
+            assert not dst.exists()
+            shutil.copy(src, dst)
+    bench = json.loads((copy / "BENCHMARK.json").read_text())
+    entries = json.loads((EXAMPLE / "entries.json").read_text())
+    bench["configs"] += entries["configs"]
+    bench["workloads"] += entries["workloads"]
+    (copy / "BENCHMARK.json").write_text(json.dumps(bench))
+    assert all(p.read_bytes() == b for p, b in before.items()
+               if p.name != "BENCHMARK.json")
+    assert collected() > before_count
+
+    code = (
+        "import json, pathlib, sys\n"
+        f"sys.path.insert(0, {str(copy / 'tests' / 'chipbench')!r})\n"
+        "import chipbench_faults\n"
+        "faults = chipbench_faults.fault_names('spmv_copy')\n"
+        "for i, fault in enumerate([None, *faults]):\n"
+        f"    tmp = pathlib.Path({str(tmp_path)!r}) / str(i)\n"
+        "    r = chipbench_faults.run('er-2k.spmv', fault, tmp)\n"
+        "    print(json.dumps([fault, r['correct']]))\n")
+    out = subprocess.run([sys.executable, "-c", code], cwd=copy, env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    runs = [json.loads(line) for line in out.stdout.splitlines()
+            if line.startswith("[")]
+    assert runs == [[None, True], ["scaled", False], ["half", False]]
 
 
 def test_unknown_workload_is_refused(tiny):
