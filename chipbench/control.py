@@ -2,7 +2,7 @@
 
     python3 chipbench/control.py --workload <name> --seeds 11,12,13
 
-For each seed: generate the cell's matrix and input pool as a run would,
+For each seed: generate the cell's data and input pool as a run would,
 compute every input's answer with the operation's ``control`` (the
 reference in bfloat16, on the default device) and print the numbers
 ``check`` compares, beside the cell's limits. The control has to read over
@@ -29,10 +29,8 @@ from chipbench import harness  # noqa: E402
 
 def readings(workload: str, seed: int, root=harness.ROOT) -> dict:
     spec = harness.load_cell(root, workload)
-    gen, op = harness.load_module(spec["gen"]), harness.load_module(spec["op"])
-    cfg, traffic = spec["config"], spec["traffic"]
-    rows, cols, vals, shape = gen.generate(cfg, seed)
-    data = harness.Data(rows, cols, vals, shape, np.dtype(cfg["dtype"]))
+    op, traffic = harness.load_module(spec["op"]), spec["traffic"]
+    data = harness.generate(spec, seed)
     worst = {}
     for inp in op.inputs(data, traffic, np.random.default_rng([seed, 1])):
         got = op.check(op.control(data, traffic, inp),

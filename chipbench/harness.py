@@ -90,6 +90,12 @@ class Data:
         self.shape = tuple(int(s) for s in shape)
         self._cache = {}
 
+    def describe(self) -> str:
+        return f"{self.shape[0]}x{self.shape[1]}, nnz {self.rows.size}"
+
+    def arrays(self) -> tuple:
+        return self.rows, self.cols, self.vals
+
     def cached(self, key, make):
         if key not in self._cache:
             self._cache[key] = make()
@@ -108,6 +114,27 @@ class Data:
 
         return self.cached("counts", lambda: floor.matrix_counts(
             self.rows, self.cols, self.shape))
+
+
+def generate(spec: dict, seed: int):
+    """The data the cell's operation receives: the one way from a generator
+    to an operation.
+
+    A generator returns either a matrix in COO form, the tuple ``(rows,
+    cols, vals, shape)``, which becomes a :class:`Data` in the
+    configuration's ``dtype``, or an object of the configuration's own
+    (a stack of layers, say), handed to the operation untouched. Such an
+    object carries its own sizes, taken from the configuration the
+    generator was given, and has what the harness and its tests ask of
+    any data: ``describe()``, a short line for the log, and ``arrays()``,
+    the numpy arrays it is made of.
+    """
+    cfg = spec["config"]
+    out = load_module(spec["gen"]).generate(cfg, seed)
+    if isinstance(out, tuple):
+        rows, cols, vals, shape = out
+        return Data(rows, cols, vals, shape, np.dtype(cfg["dtype"]))
+    return out
 
 
 def log(*parts) -> None:
@@ -228,15 +255,13 @@ def measure(spec: dict, seed: int, seconds: float, trace: bool,
 
     from chipbench import common, peaks, trace as trace_mod
 
-    cfg, traffic = spec["config"], spec["traffic"]
-    gen, op = load_module(spec["gen"]), load_module(spec["op"])
+    traffic = spec["traffic"]
+    op = load_module(spec["op"])
 
     t0 = time.perf_counter()
-    rows, cols, vals, shape = gen.generate(cfg, seed)
-    data = Data(rows, cols, vals, shape, np.dtype(cfg["dtype"]))
+    data = generate(spec, seed)
     pool = op.inputs(data, traffic, np.random.default_rng([seed, 1]))
-    log(f"generate: {time.perf_counter() - t0:.3f} s, {shape[0]}x{shape[1]}, "
-        f"nnz {data.rows.size}")
+    log(f"generate: {time.perf_counter() - t0:.3f} s, {data.describe()}")
 
     clock = Clock()
     t_setup = time.perf_counter()

@@ -1,8 +1,9 @@
 """A copy of the benchmark at tiny sizes, for running cells on the CPU.
 
 ``tiny_root(tmp)`` copies ``chipbench/`` and ``BENCHMARK.json`` and cuts
-every configuration to a few hundred rows, so every cell runs end to end
-here in a few seconds with the Pallas kernels interpreted.
+every configuration to a few hundred rows, or a stack of layers to narrow
+widths, so every cell runs end to end here in a few seconds with the
+Pallas kernels interpreted.
 
 The tiny sizes of configuration ``<config>`` are the file
 ``tests/chipbench/tiny/<config>.json``: a JSON object of the keys that

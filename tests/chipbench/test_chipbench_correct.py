@@ -63,11 +63,9 @@ def test_lower_precision_control_fails_the_limits(tmp_path, workload):
     cell's limit on every input of the pool."""
     root = chipbench_tiny.tiny_root(tmp_path)
     spec = harness.load_cell(root, workload)
-    gen, op = harness.load_module(spec["gen"]), harness.load_module(spec["op"])
-    cfg, traffic = spec["config"], spec["traffic"]
+    op, traffic = harness.load_module(spec["op"]), spec["traffic"]
     for seed in (1, 2, 3):
-        r, c, v, shape = gen.generate(cfg, seed)
-        data = harness.Data(r, c, v, shape, np.dtype(cfg["dtype"]))
+        data = harness.generate(spec, seed)
         for inp in op.inputs(data, traffic, np.random.default_rng([seed, 1])):
             ref = op.reference(data, traffic, inp)
             ctrl = op.check(op.control(data, traffic, inp), ref)
@@ -80,9 +78,8 @@ def test_reference_cg_matches_a_float64_solve(tmp_path):
 
     root = chipbench_tiny.tiny_root(tmp_path)
     spec = harness.load_cell(root, "hpcg-64.cg50")
-    gen, op = harness.load_module(spec["gen"]), harness.load_module(spec["op"])
-    r, c, v, shape = gen.generate(spec["config"], 0)
-    data = harness.Data(r, c, v, shape, np.float32)
+    op = harness.load_module(spec["op"])
+    data = harness.generate(spec, 0)
     b = op.inputs(data, spec["traffic"], np.random.default_rng(0))[0]
     x = op.reference(data, dict(spec["traffic"], maxiter=200), b)
     exact = spla.spsolve(data.csr64().tocsc(), b.astype(np.float64))

@@ -93,21 +93,21 @@ def test_tiny_four_device_cell_runs(tmp_path, workload):
 
 @pytest.mark.parametrize("workload", list(CELLS))
 def test_same_seed_same_inputs_different_seed_different_inputs(tiny, workload):
-    """The matrix is the configuration's data set, the same on every seed,
+    """The data is the configuration's data set, the same on every seed,
     so every run compiles the same programs; the seed draws the inputs."""
     import numpy as np
 
     spec = harness.load_cell(tiny, workload)
-    gen, op = harness.load_module(spec["gen"]), harness.load_module(spec["op"])
+    op = harness.load_module(spec["op"])
 
     def draw(seed):
-        r, c, v, shape = gen.generate(spec["config"], seed)
-        data = harness.Data(r, c, v, shape, np.float32)
-        return (r, c, v), op.inputs(data, spec["traffic"],
-                                    np.random.default_rng([seed, 1]))
+        data = harness.generate(spec, seed)
+        return data.arrays(), op.inputs(data, spec["traffic"],
+                                        np.random.default_rng([seed, 1]))
 
     (m1, x1), (m2, x2), (m3, x3) = draw(SEED), draw(SEED), draw(SEED + 1)
-    assert all((a == b).all() for a, b in zip(m1, m3))
+    assert m1 and len(m1) == len(m3)
+    assert all(a.shape == b.shape and (a == b).all() for a, b in zip(m1, m3))
     assert all((a == b).all() for a, b in zip(x1, x2))
     assert not any((a == b).all() for a, b in zip(x1, x3))
 
@@ -177,9 +177,13 @@ def test_a_new_cell_is_files_plus_one_workloads_entry(tiny):
     check_line(r, "er-tiny.spmv", 0, root=tiny)
 
 
-# A configuration, generator, traffic mix, operation and cell, with their
-# tiny sizes and checks, laid out as in the repository, and their entries.
-EXAMPLE = pathlib.Path(__file__).resolve().parent / "example_cell"
+# Configurations, generators, traffic mixes, operations and cells, with
+# their tiny sizes and checks, laid out as in the repository, and their
+# entries: a COO matrix (example_cell) and a stack of layers handed over as
+# tiles (example_stack).
+HERE = pathlib.Path(__file__).resolve().parent
+EXAMPLE = HERE / "example_cell"
+EXAMPLE_STACK = HERE / "example_stack"
 
 
 @pytest.mark.parametrize("config", [c["name"] for c in BENCH["configs"]])
@@ -192,12 +196,12 @@ def test_every_config_has_tiny_sizes(config):
     assert set(json.loads(path.read_text())) <= set(keys)
 
 
-def test_a_new_config_op_and_cell_need_no_edit(tmp_path):
-    """On a copy of the benchmark and its tests, a new generator,
+def join_example(example, tmp_path):
+    """On a copy of the benchmark and its tests, the example's generator,
     configuration, traffic mix, operation and cell, with their tiny sizes
-    and checks, join as new files plus one ``configs`` and one
-    ``workloads`` entry: the tests collect them, the cell's tiny run is
-    correct and each of its faults makes it not correct."""
+    and checks, join as new files plus its ``configs`` and ``workloads``
+    entries: the tests collect them, the cell's tiny run is correct and
+    each of its faults makes it not correct."""
     copy = tmp_path / "repo"
     copy.mkdir()
     shutil.copy(ROOT / "BENCHMARK.json", copy)
@@ -218,14 +222,14 @@ def test_a_new_config_op_and_cell_need_no_edit(tmp_path):
 
     before_count = collected()
     before = {p: p.read_bytes() for p in copy.rglob("*") if p.is_file()}
-    for src in EXAMPLE.rglob("*"):
+    for src in example.rglob("*"):
         if (src.is_file() and src.name != "entries.json"
                 and "__pycache__" not in src.parts):
-            dst = copy / src.relative_to(EXAMPLE)
+            dst = copy / src.relative_to(example)
             assert not dst.exists()
             shutil.copy(src, dst)
     bench = json.loads((copy / "BENCHMARK.json").read_text())
-    entries = json.loads((EXAMPLE / "entries.json").read_text())
+    entries = json.loads((example / "entries.json").read_text())
     bench["configs"] += entries["configs"]
     bench["workloads"] += entries["workloads"]
     (copy / "BENCHMARK.json").write_text(json.dumps(bench))
@@ -233,21 +237,40 @@ def test_a_new_config_op_and_cell_need_no_edit(tmp_path):
                if p.name != "BENCHMARK.json")
     assert collected() > before_count
 
+    cell = entries["workloads"][0]
+    op = json.loads((copy / "chipbench" / "traffic"
+                     / f"{cell['traffic']}.json").read_text())["op"]
     code = (
         "import json, pathlib, sys\n"
         f"sys.path.insert(0, {str(copy / 'tests' / 'chipbench')!r})\n"
         "import chipbench_faults\n"
-        "faults = chipbench_faults.fault_names('spmv_copy')\n"
+        f"faults = chipbench_faults.fault_names({op!r})\n"
+        "print(json.dumps(faults))\n"
         "for i, fault in enumerate([None, *faults]):\n"
         f"    tmp = pathlib.Path({str(tmp_path)!r}) / str(i)\n"
-        "    r = chipbench_faults.run('er-2k.spmv', fault, tmp)\n"
+        f"    r = chipbench_faults.run({cell['name']!r}, fault, tmp)\n"
         "    print(json.dumps([fault, r['correct']]))\n")
     out = subprocess.run([sys.executable, "-c", code], cwd=copy, env=env,
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]
-    runs = [json.loads(line) for line in out.stdout.splitlines()
-            if line.startswith("[")]
-    assert runs == [[None, True], ["scaled", False], ["half", False]]
+    lines = [json.loads(line) for line in out.stdout.splitlines()
+             if line.startswith("[")]
+    faults, runs = lines[0], lines[1:]
+    assert len(faults) >= 2
+    assert runs == [[None, True], *[[f, False] for f in faults]]
+    return faults
+
+
+def test_a_new_config_op_and_cell_need_no_edit(tmp_path):
+    """A COO matrix's configuration, operation and cell join as files."""
+    assert join_example(EXAMPLE, tmp_path) == ["scaled", "half"]
+
+
+def test_a_layer_stack_config_needs_no_edit(tmp_path):
+    """A configuration whose generator returns a stack of layers as tiles,
+    not a COO matrix, joins as files: its operation reads the widths from
+    the data it is handed, also at the tiny sizes."""
+    assert join_example(EXAMPLE_STACK, tmp_path) == ["no_activation", "scaled"]
 
 
 def test_unknown_workload_is_refused(tiny):
